@@ -45,7 +45,7 @@ def main() -> None:
     tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
     out = [int(tok[0, 0])]
     for _ in range(8):
-        tok, cache = serve(params, tok, cache)
+        tok, _, cache = serve(params, tok, cache)
         out.append(int(tok[0, 0]))
     print("decoded continuation ids:", out)
 

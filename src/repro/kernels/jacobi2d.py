@@ -14,7 +14,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.blocks import SUBLANES, block_size
+
 BLOCK_H = 256
+#: bytes of VMEM for the pipelined row blocks: three input views and the
+#: output, each double-buffered, so 8 blocks of (bh, C). The rest of v5e's
+#: 16 MiB default scoped VMEM holds the kernel's (bh, C) f32 temporaries;
+#: at 8192 columns this gives 32 f32 rows (64 bf16), which compiles for v5e.
+VMEM_BLOCK_BUDGET = 8 * 2**20
 
 
 def _jacobi_kernel(up_ref, mid_ref, dn_ref, out_ref, *, bh: int,
@@ -41,9 +48,10 @@ def _jacobi_kernel(up_ref, mid_ref, dn_ref, out_ref, *, bh: int,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def jacobi2d_pallas(a: jax.Array, interpret: bool = False) -> jax.Array:
     R, C = a.shape
-    bh = min(BLOCK_H, R)
-    while R % bh:      # blocks must tile the rows exactly (halo correctness)
-        bh -= 1
+    cap = VMEM_BLOCK_BUDGET // (8 * C * a.dtype.itemsize)
+    cap = max(SUBLANES, min(BLOCK_H, cap - cap % SUBLANES))
+    # blocks must tile the rows exactly (halo correctness)
+    bh = block_size(R, cap, SUBLANES, what=f"jacobi2d rows of {a.shape}")
     nb = R // bh
     kernel = functools.partial(_jacobi_kernel, bh=bh, nrows=R, ncols=C)
     return pl.pallas_call(

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.blocks import LANES, SUBLANES, block_size, round_up
+
 CHUNK_T = 128
 BLOCK_D = 512
 
@@ -54,13 +56,21 @@ def mamba_scan_pallas(dt: jax.Array, A: jax.Array, B: jax.Array,
     """dt, x: (Bt,S,D); A: (D,N); B, C: (Bt,S,N) -> y: (Bt,S,D)."""
     Bt, S, D = x.shape
     N = A.shape[1]
-    L = min(CHUNK_T, S)
-    while S % L:
-        L -= 1
-    dblk = min(BLOCK_D, D)
-    while D % dblk:
-        dblk -= 1
-    grid = (Bt, D // dblk, S // L)
+    # Time chunks and channel blocks must tile exactly and meet the tiling
+    # rule. Where no aligned divisor exists the input is zero-padded:
+    # padded steps have dt = 0 and leave the state alone, padded channels
+    # have dt = x = A = 0 and stay zero, and both are cut off the output.
+    Sp = round_up(S, SUBLANES) if S > CHUNK_T else S
+    Dp = round_up(D, LANES) if D > BLOCK_D else D
+    if (Sp, Dp) != (S, D):
+        dt = jnp.pad(dt, ((0, 0), (0, Sp - S), (0, Dp - D)))
+        x = jnp.pad(x, ((0, 0), (0, Sp - S), (0, Dp - D)))
+        B = jnp.pad(B, ((0, 0), (0, Sp - S), (0, 0)))
+        C = jnp.pad(C, ((0, 0), (0, Sp - S), (0, 0)))
+        A = jnp.pad(A, ((0, Dp - D), (0, 0)))
+    L = block_size(Sp, CHUNK_T, SUBLANES, what="mamba scan time")
+    dblk = block_size(Dp, BLOCK_D, LANES, what="mamba scan channels")
+    grid = (Bt, Dp // dblk, Sp // L)
     kern = functools.partial(_scan_kernel, L=L)
     return pl.pallas_call(
         kern,
@@ -73,7 +83,7 @@ def mamba_scan_pallas(dt: jax.Array, A: jax.Array, B: jax.Array,
             pl.BlockSpec((dblk, N), lambda b, d, t: (d, 0)),        # A
         ],
         out_specs=pl.BlockSpec((1, L, dblk), lambda b, d, t: (b, t, d)),
-        out_shape=jax.ShapeDtypeStruct((Bt, S, D), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((Bt, Sp, Dp), x.dtype),
         scratch_shapes=[pltpu.VMEM((dblk, N), jnp.float32)],
         interpret=interpret,
-    )(dt, B, C, x, A)
+    )(dt, B, C, x, A)[:, :S, :D]

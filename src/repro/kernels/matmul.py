@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.blocks import LANES, block_size, round_up
+
 BM, BN, BK = 256, 256, 512
 
 
@@ -44,9 +46,16 @@ def matmul_pallas(a: jax.Array, b: jax.Array,
     M, K = a.shape
     K2, N = b.shape
     assert K == K2
-    bm, bn, bk = min(BM, M), min(BN, N), min(BK, K)
-    while K % bk:   # K blocks must tile exactly: padded K lanes would
-        bk -= 1     # contribute unspecified values to the accumulation
+    bm, bn = min(BM, M), min(BN, N)
+    # K blocks must tile exactly: the out-of-bounds lanes of a partial K
+    # block would add unspecified values to the accumulation. A K above
+    # BK that is no multiple of 128 is zero-padded, which adds nothing.
+    if K > BK and K % LANES:
+        pad = round_up(K, LANES) - K
+        a = jnp.pad(a, ((0, 0), (0, pad)))
+        b = jnp.pad(b, ((0, pad), (0, 0)))
+        K += pad
+    bk = block_size(K, BK, LANES, what="matmul K")
     nk = K // bk
     grid = (pl.cdiv(M, bm), pl.cdiv(N, bn), nk)
     return pl.pallas_call(

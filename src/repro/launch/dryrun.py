@@ -207,8 +207,6 @@ def build_cell(arch: str, shape_name: str, mesh, *,
             lambda: init_cache(cfg, B, S))
         cshard = shd.named(mesh,
                            shd.cache_specs(cache_abs, B, dp, dpn, sizes["model"]))
-        logits_shard = jax.NamedSharding(mesh, shd.batch_spec(B, dp, dpn,
-                                                              extra_dims=2))
         args_list = [params_abs, specs["tokens"]]
         in_sh = [pshard, bshard]
         if "ctx" in specs or "frames" in specs:
@@ -216,7 +214,7 @@ def build_cell(arch: str, shape_name: str, mesh, *,
             in_sh.append(ctx_shard)
         jitted = jax.jit(
             step, in_shardings=tuple(in_sh),
-            out_shardings=(logits_shard, cshard))
+            out_shardings=(bshard, bshard, cshard))
         args = tuple(args_list)
 
     else:  # decode
@@ -231,7 +229,7 @@ def build_cell(arch: str, shape_name: str, mesh, *,
             in_sh.append(ctx_shard)
         jitted = jax.jit(
             step, in_shardings=tuple(in_sh),
-            out_shardings=(bshard, cshard),
+            out_shardings=(bshard, bshard, cshard),
             donate_argnums=(2,),
         )
         args = tuple(args_list)

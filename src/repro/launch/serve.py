@@ -1,7 +1,12 @@
 """Serving launcher: prefill a batch of prompts, then greedy-decode.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --reduced \
+    PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b --reduced \
         --batch 4 --prompt-len 16 --decode 16
+
+Both steps are compiled ahead of time and reported as set-up; prefill and
+decode times wait for the device (``block_until_ready``). Off the chip run
+it with ``JAX_PLATFORMS=cpu``; `chip_smoke.py` at the repo root drives the
+same `serve` function at granite-3-2b's full widths on one TPU.
 
 With ``--svm-budget-frac`` the decode loop additionally rides the SVM
 weight-streaming runtime: the model's parameter leaves are planned into
@@ -14,7 +19,7 @@ back to per-token `decode_step` replays), reporting the simulated
 streaming wall clock, migration/eviction traffic, and session cache stats
 next to the real tok/s.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --reduced \
+    PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b --reduced \
         --svm-budget-frac 0.6 --svm-mode svm_aware
 
 With ``--requests N`` (N > 1) the report switches to the **multi-tenant
@@ -25,7 +30,7 @@ shared SVM pool under ``--sched-policy fifo|admission|svm_aware`` —
 per-request latency percentiles, aggregate tok/s, and eviction pressure
 ride along the real decode's tok/s.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --reduced \
+    PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b --reduced \
         --svm-budget-frac 0.6 --requests 8 --sched-policy svm_aware
 """
 
@@ -40,6 +45,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.data import SyntheticLM, modality_stub
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models import init_params
@@ -115,7 +121,7 @@ class WeightStream:
     def report(self, decoded: int) -> str:
         m = self.executor.metrics()
         return (
-            f"svm stream: DOS {m['dos']:.0f}% "
+            f"svm stream (simulated): DOS {m['dos']:.0f}% "
             f"(pool {self.budget / 1e6:.1f}MB / "
             f"weights {self.total_bytes / 1e6:.1f}MB), "
             f"simulated decode wall {m['wall_s'] * 1e3:.2f}ms, "
@@ -131,18 +137,19 @@ def decode_tokens(cfg, serve_step, params, tok, cache, ctx, steps: int):
 
     Encoder-decoder configs re-encode their modality context and thread
     it through every step; VLMs thread the precomputed image context.
-    Decoder-only configs (``ctx`` is None) take the two-argument path.
-    Returns (decoded token list, final cache)."""
-    outs = []
+    Decoder-only configs (``ctx`` is None) take the three-argument path.
+    Returns (decoded token list, per-step logits list, final cache)."""
+    outs, logits = [], []
     for _ in range(steps):
         if ctx is not None and (cfg.is_encdec or cfg.is_vlm):
             from repro.models import encode
             c = encode(params, cfg, ctx) if cfg.is_encdec else ctx
-            tok, cache = serve_step(params, tok, cache, c)
+            tok, lg, cache = serve_step(params, tok, cache, c)
         else:
-            tok, cache = serve_step(params, tok, cache)
+            tok, lg, cache = serve_step(params, tok, cache)
         outs.append(tok)
-    return outs, cache
+        logits.append(lg)
+    return outs, logits, cache
 
 
 def _chaos_line(r: dict) -> str:
@@ -168,7 +175,7 @@ def schedule_report(r: dict) -> str:
     chaos/recovery line when a fault plan was injected)."""
     sc = r["shared_cache"]
     return (
-        f"svm sched[{r['policy']}]: {r['n_requests']} reqs, "
+        f"svm sched[{r['policy']}] (simulated): {r['n_requests']} reqs, "
         f"offered DOS {r['dos_offered']:.0f}% "
         f"(peak admitted {r['dos_peak']:.0f}%), "
         f"p50/p90/p99 latency "
@@ -189,9 +196,9 @@ def schedule_report(r: dict) -> str:
         + _chaos_line(r))
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="gemma3-1b", choices=list(ARCH_IDS))
+    ap.add_argument("--arch", default="granite-3-2b", choices=list(ARCH_IDS))
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -233,15 +240,54 @@ def main() -> None:
                     help="evictions-per-token watermark for the runtime "
                          "thrash guard (preempt + tighten admission); "
                          "unset = guard off")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.requests > 1 and args.svm_budget_frac <= 0.0:
         ap.error("--requests > 1 needs --svm-budget-frac > 0 "
                  "(the shared pool is sized from it)")
+    return args
 
+
+def compile_steps(cfg, params, prompts, ctx, cache_len: int):
+    """Compile the prefill step and the decode step ahead of time.
+
+    Arguments may be arrays or `jax.ShapeDtypeStruct`s (with shardings on
+    described devices, for compiling without the chip). The decode step
+    donates its cache argument, so one step holds one copy of the cache.
+    Returns (prefill, decode) compiled executables; the decode step takes
+    the context that `decode_tokens` threads (encoded, for enc-dec)."""
+    from repro.models import encode
+
+    pre_args = (params, prompts) + ((ctx,) if ctx is not None else ())
+    lowered = jax.jit(make_prefill_step(cfg, cache_len)).lower(*pre_args)
+    prefill_c = lowered.compile()
+    tok, _, cache = jax.tree.map(
+        lambda info, sh: jax.ShapeDtypeStruct(info.shape, info.dtype,
+                                              sharding=sh),
+        lowered.out_info, prefill_c.output_shardings)
+    dec_args = (params, tok, cache)
+    if ctx is not None:
+        dec_args += ((jax.eval_shape(lambda p, c: encode(p, cfg, c),
+                                     params, ctx)
+                      if cfg.is_encdec else ctx),)
+    serve_c = jax.jit(make_serve_step(cfg),
+                      donate_argnums=(2,)).lower(*dec_args).compile()
+    return prefill_c, serve_c
+
+
+def serve(args: argparse.Namespace) -> dict:
+    """Run the serving path once: prefill a batch of prompts, greedy-decode
+    ``args.decode`` tokens through the cache, and ride the SVM accounting
+    (and, with ``args.requests > 1``, the multi-tenant schedule) along.
+
+    Both steps are compiled before any clock starts (``compile_s``), and
+    every clock read waits for the device. ``logits`` holds the prefill's
+    last-position logits and each decode step's, (B, decode + 1, V), and
+    ``tokens`` the greedy continuation they picked."""
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     mesh = (make_production_mesh() if args.production_mesh
             else make_host_mesh())
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = jax.jit(init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(0))
 
     stream = None
     if args.svm_budget_frac > 0.0:
@@ -261,34 +307,52 @@ def main() -> None:
         ctx = jnp.asarray(modality_stub("frames", args.batch,
                                         cfg.encoder_frames, cfg.d_model),
                           jnp.bfloat16)
-
-    prefill_jit = jax.jit(make_prefill_step(cfg))
-    serve_jit = jax.jit(make_serve_step(cfg))
+    pre_args = (params, prompts) + ((ctx,) if ctx is not None else ())
 
     with mesh:
-        t0 = time.time()
-        if ctx is not None:
-            logits, cache = prefill_jit(params, prompts, ctx)
-        else:
-            logits, cache = prefill_jit(params, prompts)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        t_pre = time.time() - t0
-        t0 = time.time()
-        decoded, cache = decode_tokens(cfg, serve_jit, params, tok, cache,
-                                       ctx, args.decode)
-        outs = [tok] + decoded
-        t_dec = time.time() - t0
+        jax.block_until_ready(pre_args)
+        t0 = time.perf_counter()
+        prefill_c, serve_c = compile_steps(
+            cfg, params, prompts, ctx, args.prompt_len + args.decode)
+        t_compile = time.perf_counter() - t0
+
+        t0 = time.perf_counter()
+        tok, logits, cache = prefill_c(*pre_args)
+        jax.block_until_ready((tok, logits, cache))
+        t_pre = time.perf_counter() - t0
+
+        t0 = time.perf_counter()
+        decoded, step_logits, cache = decode_tokens(
+            cfg, serve_c, params, tok, cache, ctx, args.decode)
+        jax.block_until_ready((decoded, step_logits, cache))
+        t_dec = time.perf_counter() - t0
         # the streaming accounting is a pure function of the token count:
         # replay it outside the timed loop so tok/s stays the real number
         if stream is not None:
             stream.steps(args.decode)
 
-    seq = jnp.concatenate(outs, axis=1)
-    print(f"prefill {args.batch}x{args.prompt_len} in {t_pre*1e3:.1f}ms; "
-          f"decoded {args.decode} tokens in {t_dec*1e3:.1f}ms "
-          f"({args.batch*args.decode/max(t_dec,1e-9):.1f} tok/s)")
-    if stream is not None:
-        print(stream.report(args.decode))
+    dev = jax.devices()[0]
+    stats = dev.memory_stats() or {}
+    res = {
+        "cfg": cfg,
+        "batch": args.batch,
+        "prompt_len": args.prompt_len,
+        "decode": args.decode,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "compile_s": t_compile,
+        "prefill_s": t_pre,
+        "decode_s": t_dec,
+        "decode_per_token_s": t_dec / max(args.decode, 1),
+        "tok_s": args.batch * args.decode / max(t_dec, 1e-9),
+        "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+        "params": params,
+        "prompts": prompts,
+        "tokens": jnp.concatenate([tok] + decoded, axis=1),
+        "logits": jnp.stack([logits] + step_logits, axis=1),
+        "stream": stream.report(args.decode) if stream is not None else None,
+        "schedule": None,
+    }
     if args.requests > 1:
         # multi-tenant accounting: N requests of this model contending
         # for one shared pool (pure simulation — rides the same clock
@@ -302,14 +366,47 @@ def main() -> None:
                                      n_requests=args.requests,
                                      tokens=args.decode,
                                      intensity=args.chaos_intensity)
-        sched = run_schedule(
+        res["schedule"] = run_schedule(
             [spec], args.requests, pool, policy=args.sched_policy,
             admit_by=args.admit_by,
             seed=0, mean_interarrival_s=args.arrival,
             tokens=args.decode, evict_policy=args.svm_policy,
             fault_plan=plan, thrash_watermark=args.thrash_watermark)
-        print(schedule_report(sched))
-    print("first request continuation:", seq[0].tolist())
+    return res
+
+
+def report_lines(res: dict) -> list[str]:
+    """Human summary of a `serve` result: device, widths, set-up and
+    device-timed step times, peak device bytes, simulated SVM reports."""
+    c = res["cfg"]
+    dev = res["device"]
+    peak = res["peak_bytes_in_use"]
+    lines = [
+        f"device: {dev['platform']} {dev['kind']} x{dev['count']}",
+        f"model: {c.name} {c.n_layers} layers, d_model {c.d_model}, "
+        f"{c.n_heads} heads ({c.n_kv_heads} kv), d_ff {c.d_ff}, "
+        f"vocab {c.vocab}",
+        f"compile (set-up): {res['compile_s']:.3f}s",
+        f"prefill {res['batch']}x{res['prompt_len']}: "
+        f"{res['prefill_s'] * 1e3:.3f}ms; decode {res['decode']} tokens: "
+        f"{res['decode_per_token_s'] * 1e3:.3f}ms/token "
+        f"({res['tok_s']:.1f} tok/s)",
+        "peak device bytes: "
+        + (f"{peak}" if peak is not None else "not reported by backend"),
+    ]
+    if res["stream"] is not None:
+        lines.append(res["stream"])
+    if res["schedule"] is not None:
+        lines.append(schedule_report(res["schedule"]))
+    return lines
+
+
+def main(argv: list[str] | None = None) -> None:
+    args = parse_args(argv)
+    enable_compile_cache()
+    res = serve(args)
+    print("\n".join(report_lines(res)))
+    print("first request continuation:", res["tokens"][0].tolist())
 
 
 if __name__ == "__main__":

@@ -126,27 +126,38 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptConfig,
 
 
 def make_serve_step(cfg: ModelConfig):
-    """Returns serve_step(params, token, cache[, ctx]) -> (next_ids, cache):
-    one greedy decode step over a seq_len-deep KV/SSM cache."""
+    """Returns serve_step(params, token, cache[, ctx]) -> (next_ids, logits,
+    cache): one greedy decode step over a seq_len-deep KV/SSM cache.
+    ``logits`` (B, V) are the step's own; serving samples ``next_ids`` from
+    them, and a correctness check compares them against `forward`."""
 
     def serve_step(params, token, cache, ctx=None):
         logits, cache = decode_step(params, cfg, token, cache, ctx=ctx)
-        next_ids = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        return next_ids[:, None], cache
+        last = logits[:, -1]
+        next_ids = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        return next_ids[:, None], last, cache
 
     return serve_step
 
 
-def make_prefill_step(cfg: ModelConfig):
-    """Returns prefill_step(params, tokens[, ctx]) -> (last_logits, cache).
-    Only the final position's logits are returned — serving samples from
-    them, and a full (B,S,V) logits output would dominate the step's output
-    bytes (537 GB for a 256k vocab at 32k prefill)."""
+def make_prefill_step(cfg: ModelConfig, cache_len: int | None = None):
+    """Returns prefill_step(params, tokens[, ctx]) -> (next_ids, last_logits,
+    cache). Only the final position's logits are returned — serving samples
+    from them, and a full (B,S,V) logits output would dominate the step's
+    output bytes (537 GB for a 256k vocab at 32k prefill).
+
+    ``cache_len`` is the decode cache's width: the prompt plus every token
+    that will be decoded into it. It defaults to the prompt length, which
+    leaves no free slot: the first decoded token then overwrites the
+    prompt's oldest position."""
     from repro.models import prefill
 
     def prefill_step(params, tokens, ctx=None):
         c = encode(params, cfg, ctx) if cfg.is_encdec else ctx
-        logits, cache = prefill(params, cfg, tokens, ctx=c)
-        return logits[:, -1:], cache
+        logits, cache = prefill(params, cfg, tokens, ctx=c,
+                                cache_len=cache_len)
+        last = logits[:, -1]
+        next_ids = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        return next_ids[:, None], last, cache
 
     return prefill_step

@@ -21,6 +21,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.data import SyntheticLM, modality_stub
 from repro.ft import TrainSupervisor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.settings import settings_for
 from repro.launch.steps import make_train_step
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--ckpt", default="/tmp/repro_train_ckpt")
     ap.add_argument("--production-mesh", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     st = settings_for(args.arch)

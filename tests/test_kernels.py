@@ -131,6 +131,59 @@ def test_property_mamba_scan_matches_oracle(s, d, n):
                                rtol=2e-3, atol=2e-3)
 
 
+# ------------------------------------------------------- aligned block choice
+# Shapes whose old count-down loops landed on blocks that break the TPU's
+# (8,128) tiling rule (jacobi rows 1000 -> 250, matmul K 1000 -> 500,
+# mamba channels 1000 -> 500). They now take aligned blocks or zero-pad.
+
+def test_block_size_rule():
+    from repro.kernels.blocks import block_size
+    assert block_size(100, 256, 8) == 100            # whole dim fits
+    assert block_size(1000, 256, 8) == 200           # aligned divisor
+    assert block_size(384, 256, 8) == 192
+    with pytest.raises(ValueError, match="1001"):
+        block_size(1001, 256, 8)
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16])
+def test_jacobi2d_unaligned_rows(dtype):
+    a = _rand(14, (1000, 1024), dtype)
+    out = jacobi2d_pallas(a, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32),
+        np.asarray(ref.jacobi2d_ref(a), np.float32), **_tol(dtype))
+
+
+def test_jacobi2d_without_aligned_block_names_shape():
+    with pytest.raises(ValueError, match=r"\(1001, 128\)"):
+        jacobi2d_pallas(jnp.zeros((1001, 128), F32), interpret=True)
+
+
+@pytest.mark.parametrize("mnk", [(256, 256, 1000), (64, 128, 700)])
+def test_matmul_unaligned_k_is_zero_padded(mnk):
+    m, n, k = mnk
+    a, b = _rand(15, (m, k), F32), _rand(16, (k, n), F32)
+    out = matmul_pallas(a, b, interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.matmul_ref(a, b)),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("dims", [(1, 128, 1000, 16), (1, 130, 256, 8)])
+def test_mamba_scan_unaligned_dims_are_zero_padded(dims):
+    Bt, S, D, N = dims
+    dt = jax.nn.softplus(_rand(17, (Bt, S, D), F32))
+    A = -jnp.exp(_rand(18, (D, N), F32) * 0.3)
+    B = _rand(19, (Bt, S, N), F32)
+    C = _rand(20, (Bt, S, N), F32)
+    x = _rand(21, (Bt, S, D), F32)
+    out = mamba_scan_pallas(dt, A, B, C, x, interpret=True)
+    assert out.shape == (Bt, S, D)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.mamba_scan_ref(dt, A, B, C, x)),
+                               rtol=2e-3, atol=2e-3)
+
+
 # ------------------------------------------------------------ ops dispatch
 
 def test_ops_dispatch_jnp_path():
@@ -140,3 +193,15 @@ def test_ops_dispatch_jnp_path():
                                np.asarray(ref.matmul_ref(a, b)))
     with pytest.raises(ValueError):
         ops.matmul(a, b, impl="bogus")
+
+
+def test_ops_dispatch_pallas_needs_explicit_interpret():
+    """The Pallas path runs the kernel as asked: interpret mode only when
+    the caller passes ``interpret=True``, on any backend."""
+    from repro.kernels import ops
+    a, b = _rand(22, (128, 128), F32), _rand(23, (128, 128), F32)
+    np.testing.assert_allclose(
+        np.asarray(ops.matmul(a, b, impl="pallas", interpret=True)),
+        np.asarray(ref.matmul_ref(a, b)), rtol=1e-4, atol=1e-4)
+    with pytest.raises(ValueError):
+        ops.matmul(a, b, impl="auto")

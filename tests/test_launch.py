@@ -133,9 +133,10 @@ def test_train_and_serve_compile_on_host_mesh():
     serve = make_serve_step(cfg)
     cache = init_cache(cfg, 2, 16)
     with mesh:
-        ids, cache = jax.jit(serve)(params, jnp.zeros((2, 1), jnp.int32),
-                                    cache)
+        ids, logits, cache = jax.jit(serve)(
+            params, jnp.zeros((2, 1), jnp.int32), cache)
     assert ids.shape == (2, 1)
+    assert logits.shape == (2, cfg.padded_vocab)
 
 
 def test_cell_skip_table():

@@ -88,13 +88,14 @@ class _Step:
 
     def __call__(self, params, tok, cache, ctx=_Cfg):   # sentinel default
         self.calls.append(ctx)
-        return tok + 1, cache
+        return tok + 1, -tok, cache
 
 
 def test_decoder_only_takes_two_arg_path():
     step = _Step()
-    outs, cache = decode_tokens(_Cfg(), step, {}, 0, "kv", None, 3)
+    outs, logits, cache = decode_tokens(_Cfg(), step, {}, 0, "kv", None, 3)
     assert outs == [1, 2, 3] and cache == "kv"
+    assert logits == [0, -1, -2]
     assert step.calls == [_Cfg, _Cfg, _Cfg]      # ctx never passed
 
 
@@ -141,3 +142,82 @@ def test_schedule_report_mentions_key_fields():
     assert "svm sched[fifo]" in rep
     assert f"{r['migrations']} migs / {r['evictions']} evicts" in rep
     assert f"{r['segment_shared_hits']} cross-request replays" in rep
+
+
+# ------------------------------------------- the served path on the CPU
+
+def _chip_smoke():
+    """The repo-root `chip_smoke.py`, loaded by path (it is no package)."""
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_served_decode_logits_match_forward():
+    """Prefill plus decode through the served path's cache gives the
+    logits that `forward` gives over the same tokens. The prompt-wide
+    cache the path used to build let the first decoded token overwrite
+    prompt position 0 and missed by 0.11-0.23 here."""
+    import jax.numpy as jnp
+
+    from repro.launch.serve import parse_args, serve
+    from repro.models import forward
+
+    res = serve(parse_args(["--arch", "granite-3-2b", "--reduced",
+                            "--batch", "2", "--prompt-len", "8",
+                            "--decode", "3"]))
+    cfg = res["cfg"]
+    assert res["tokens"].shape == (2, 4)
+    assert res["logits"].shape == (2, 4, cfg.padded_vocab)
+    seq = jnp.concatenate([res["prompts"], res["tokens"][:, :-1]], axis=1)
+    ref = forward(res["params"], cfg, seq)[0][:, 7:]
+    np.testing.assert_allclose(
+        np.asarray(res["logits"][..., :cfg.vocab], np.float32),
+        np.asarray(ref[..., :cfg.vocab], np.float32), rtol=0, atol=1e-2)
+    # the served tokens are the greedy picks of those logits
+    np.testing.assert_array_equal(
+        np.asarray(res["tokens"]),
+        np.asarray(jnp.argmax(res["logits"], axis=-1)))
+
+
+def test_chip_smoke_path_on_cpu_at_reduced_size():
+    """`chip_smoke.py`'s own path, steered to the reduced config: the
+    same serve arguments and the same logits check, without the backend
+    check that keeps its `main` off the CPU."""
+    cs = _chip_smoke()
+    res, chk = cs.smoke(cs.SERVE_ARGS + ["--reduced"])
+    assert chk["ok"], chk
+    assert chk["positions"] == 33
+    assert res["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert res["stream"].startswith("svm stream (simulated)")
+    assert res["schedule"]["n_requests"] == 8
+    assert res["compile_s"] > 0 and res["prefill_s"] > 0
+    assert res["tok_s"] == pytest.approx(8 * 32 / res["decode_s"])
+
+
+def test_logits_check_fails_a_prompt_wide_cache(monkeypatch):
+    """The check's tolerance is tight enough to catch the old cache,
+    which was as wide as the prompt: decoded tokens then overwrote the
+    prompt's oldest positions."""
+    import repro.launch.serve as serve_mod
+    from repro.launch import steps
+
+    make = steps.make_prefill_step
+    monkeypatch.setattr(serve_mod, "make_prefill_step",
+                        lambda cfg, cache_len=None: make(cfg))
+    cs = _chip_smoke()
+    _, chk = cs.smoke(cs.SERVE_ARGS + ["--reduced"])
+    assert not chk["ok"], chk
+
+
+def test_chip_smoke_refuses_a_cpu_backend(capsys):
+    cs = _chip_smoke()
+    assert cs.main() == 1
+    out = capsys.readouterr()
+    assert '"ok"' not in out.out
+    assert "needs a TPU" in out.err
