@@ -1,0 +1,10 @@
+"""Puts the checkout's root (for ``chipbench``) and ``src`` (for the
+program) on the import path, however pytest was started."""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
