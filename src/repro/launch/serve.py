@@ -138,17 +138,22 @@ def decode_tokens(cfg, serve_step, params, tok, cache, ctx, steps: int):
     Encoder-decoder configs re-encode their modality context and thread
     it through every step; VLMs thread the precomputed image context.
     Decoder-only configs (``ctx`` is None) take the three-argument path.
-    Returns (decoded token list, per-step logits list, final cache)."""
+    Returns (decoded token list, per-step logits list, final cache).
+
+    Each turn of the loop is a host span ``serve.step`` (argument ``step``,
+    from 0) in the profiler's trace, on the device trace's clock; with no
+    profiler running it costs a check."""
     outs, logits = [], []
-    for _ in range(steps):
-        if ctx is not None and (cfg.is_encdec or cfg.is_vlm):
-            from repro.models import encode
-            c = encode(params, cfg, ctx) if cfg.is_encdec else ctx
-            tok, lg, cache = serve_step(params, tok, cache, c)
-        else:
-            tok, lg, cache = serve_step(params, tok, cache)
-        outs.append(tok)
-        logits.append(lg)
+    for i in range(steps):
+        with jax.profiler.TraceAnnotation("serve.step", step=i):
+            if ctx is not None and (cfg.is_encdec or cfg.is_vlm):
+                from repro.models import encode
+                c = encode(params, cfg, ctx) if cfg.is_encdec else ctx
+                tok, lg, cache = serve_step(params, tok, cache, c)
+            else:
+                tok, lg, cache = serve_step(params, tok, cache)
+            outs.append(tok)
+            logits.append(lg)
     return outs, logits, cache
 
 

@@ -133,8 +133,9 @@ def make_serve_step(cfg: ModelConfig):
 
     def serve_step(params, token, cache, ctx=None):
         logits, cache = decode_step(params, cfg, token, cache, ctx=ctx)
-        last = logits[:, -1]
-        next_ids = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        with jax.named_scope("lm_head"):
+            last = logits[:, -1]
+            next_ids = jnp.argmax(last, axis=-1).astype(jnp.int32)
         return next_ids[:, None], last, cache
 
     return serve_step
@@ -156,8 +157,9 @@ def make_prefill_step(cfg: ModelConfig, cache_len: int | None = None):
         c = encode(params, cfg, ctx) if cfg.is_encdec else ctx
         logits, cache = prefill(params, cfg, tokens, ctx=c,
                                 cache_len=cache_len)
-        last = logits[:, -1]
-        next_ids = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        with jax.named_scope("lm_head"):
+            last = logits[:, -1]
+            next_ids = jnp.argmax(last, axis=-1).astype(jnp.int32)
         return next_ids[:, None], last, cache
 
     return prefill_step

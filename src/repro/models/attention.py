@@ -181,9 +181,10 @@ def self_attention(
     k_new = jnp.swapaxes(k, 1, 2)                              # (B,KV,1,hd)
     v_new = jnp.swapaxes(v, 1, 2)
     bidx = jnp.arange(B)
-    ck = cache["k"].at[bidx, :, slot].set(k_new[:, :, 0])
-    cv = cache["v"].at[bidx, :, slot].set(v_new[:, :, 0])
-    cpos = cache["pos"].at[bidx, slot].set(qpos.astype(jnp.int32))
+    with jax.named_scope("kv_cache"):
+        ck = cache["k"].at[bidx, :, slot].set(k_new[:, :, 0])
+        cv = cache["v"].at[bidx, :, slot].set(v_new[:, :, 0])
+        cpos = cache["pos"].at[bidx, slot].set(qpos.astype(jnp.int32))
     scores = _gqa_scores(q, jnp.swapaxes(ck, 1, 2))            # (B,KV,G,1,W)
     tp = cpos[:, None, None, None, :]
     qp = qpos[:, None, None, None, None]

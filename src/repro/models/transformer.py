@@ -6,10 +6,19 @@ periods are executed with `jax.lax.scan` over stacked parameters so HLO size
 and compile time are independent of depth. Layers that do not fill a whole
 period are unrolled at the end ("remainder"). KV/SSM caches follow the same
 layout (leading n_periods axis), so prefill and decode also scan.
+
+Prefill and decode name their parts with `jax.named_scope`, which XLA keeps
+in each compiled op's ``op_name``: ``layers`` (the layer stack, and with it
+the scan's carry and its stacking of the cache), ``attention`` and ``mlp``
+(each sublayer with its norm and residual add), ``kv_cache`` (inside
+``attention``: the writes into the decode cache) and ``lm_head`` (final
+norm and head). A profiler trace attributes device time by these names
+(docs/serving.md, "Tracing the serving path").
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Optional
 
@@ -49,6 +58,13 @@ UNROLL_PERIODS = False
 
 def _period_slice(pparams: PyTree, i: int) -> PyTree:
     return jax.tree.map(lambda x: x[i], pparams)
+
+
+def _scope(name: str, on: bool = True):
+    """``jax.named_scope(name)`` where ``on``, else no scope. A sublayer's
+    scope holds its residual add: XLA names a projection fused with that
+    add after the add."""
+    return jax.named_scope(name) if on else contextlib.nullcontext()
 
 
 def _anchor(x: Array) -> Array:
@@ -155,38 +171,41 @@ def _apply_layer(
     decode: bool,
 ) -> tuple[Array, Optional[dict], Array]:
     """One residual layer. Returns (x, cache_out, moe_aux)."""
-    h = rms_norm(x, lp["norm1"], cfg.norm_eps)
     cache_out: Optional[dict] = None
-    if mixer in (ATTN, ATTN_LOCAL):
-        window = cfg.sliding_window if mixer == ATTN_LOCAL else 0
-        o, kv = attn_lib.self_attention(
-            lp["mixer"], cfg, h, positions=positions, window=window,
-            theta=_theta_for(cfg, mixer),
-            cache=cache if decode else None)
-        cache_out = kv
-    elif mixer == CROSS:
-        o = attn_lib.cross_attention(lp["mixer"], cfg, h, ctx)
-        o = o * jnp.tanh(lp["gate"].astype(jnp.float32)).astype(o.dtype) \
-            if "gate" in lp else o
-        cache_out = {}
-    elif mixer == MAMBA:
-        if decode:
-            o, cache_out = mamba_lib.mamba_decode_step(lp["mixer"], cfg, h,
-                                                       cache)
+    attn = mixer in (ATTN, ATTN_LOCAL)
+    with _scope("attention", attn):
+        h = rms_norm(x, lp["norm1"], cfg.norm_eps)
+        if attn:
+            window = cfg.sliding_window if mixer == ATTN_LOCAL else 0
+            o, kv = attn_lib.self_attention(
+                lp["mixer"], cfg, h, positions=positions, window=window,
+                theta=_theta_for(cfg, mixer),
+                cache=cache if decode else None)
+            cache_out = kv
+        elif mixer == CROSS:
+            o = attn_lib.cross_attention(lp["mixer"], cfg, h, ctx)
+            o = o * jnp.tanh(lp["gate"].astype(jnp.float32)).astype(o.dtype) \
+                if "gate" in lp else o
+            cache_out = {}
+        elif mixer == MAMBA:
+            if decode:
+                o, cache_out = mamba_lib.mamba_decode_step(lp["mixer"], cfg,
+                                                           h, cache)
+            else:
+                o = mamba_lib.mamba_forward(lp["mixer"], cfg, h)
+                cache_out = None  # prefill state handled separately
         else:
-            o = mamba_lib.mamba_forward(lp["mixer"], cfg, h)
-            cache_out = None  # prefill state handled separately
-    else:
-        raise ValueError(mixer)
-    x = x + o
+            raise ValueError(mixer)
+        x = x + o
     aux = jnp.zeros((), jnp.float32)
     if ffn in (MLP, MOE):
-        h2 = rms_norm(x, lp["norm2"], cfg.norm_eps)
-        if ffn == MLP:
-            f = mlp_apply(lp["ffn"], h2, cfg.act)
-        else:
-            f, aux = moe_lib.moe_apply(lp["ffn"], cfg, h2)
-        x = x + f
+        with _scope("mlp", ffn == MLP):
+            h2 = rms_norm(x, lp["norm2"], cfg.norm_eps)
+            if ffn == MLP:
+                f = mlp_apply(lp["ffn"], h2, cfg.act)
+            else:
+                f, aux = moe_lib.moe_apply(lp["ffn"], cfg, h2)
+            x = x + f
     return x, cache_out, aux
 
 
@@ -343,30 +362,35 @@ def prefill(params: PyTree, cfg: ModelConfig, tokens: Array,
     positions = jnp.arange(S, dtype=jnp.int32)[None, :]
 
     def run_layer(lp, xc, mixer, fkind):
-        h = rms_norm(xc, lp["norm1"], cfg.norm_eps)
-        if mixer in (ATTN, ATTN_LOCAL):
-            window = cfg.sliding_window if mixer == ATTN_LOCAL else 0
-            o, kv = attn_lib.self_attention(
-                lp["mixer"], cfg, h, positions=positions, window=window,
-                theta=_theta_for(cfg, mixer))
-            W = CL if (mixer == ATTN or not cfg.sliding_window) \
-                else min(cfg.sliding_window, CL)
-            c_out = _kv_to_buffer(kv, W)
-        elif mixer == CROSS:
-            o = attn_lib.cross_attention(lp["mixer"], cfg, h, ctx)
-            o = o * jnp.tanh(lp["gate"].astype(jnp.float32)).astype(o.dtype)
-            c_out = {}
-        elif mixer == MAMBA:
-            o, c_out = mamba_lib.mamba_forward(lp["mixer"], cfg, h,
-                                               return_state=True)
-        else:
-            raise ValueError(mixer)
-        xc = xc + o
+        attn = mixer in (ATTN, ATTN_LOCAL)
+        with _scope("attention", attn):
+            h = rms_norm(xc, lp["norm1"], cfg.norm_eps)
+            if attn:
+                window = cfg.sliding_window if mixer == ATTN_LOCAL else 0
+                o, kv = attn_lib.self_attention(
+                    lp["mixer"], cfg, h, positions=positions, window=window,
+                    theta=_theta_for(cfg, mixer))
+                W = CL if (mixer == ATTN or not cfg.sliding_window) \
+                    else min(cfg.sliding_window, CL)
+                with jax.named_scope("kv_cache"):
+                    c_out = _kv_to_buffer(kv, W)
+            elif mixer == CROSS:
+                o = attn_lib.cross_attention(lp["mixer"], cfg, h, ctx)
+                o = o * jnp.tanh(
+                    lp["gate"].astype(jnp.float32)).astype(o.dtype)
+                c_out = {}
+            elif mixer == MAMBA:
+                o, c_out = mamba_lib.mamba_forward(lp["mixer"], cfg, h,
+                                                   return_state=True)
+            else:
+                raise ValueError(mixer)
+            xc = xc + o
         if fkind in (MLP, MOE):
-            h2 = rms_norm(xc, lp["norm2"], cfg.norm_eps)
-            f = (mlp_apply(lp["ffn"], h2, cfg.act) if fkind == MLP
-                 else moe_lib.moe_apply(lp["ffn"], cfg, h2)[0])
-            xc = xc + f
+            with _scope("mlp", fkind == MLP):
+                h2 = rms_norm(xc, lp["norm2"], cfg.norm_eps)
+                f = (mlp_apply(lp["ffn"], h2, cfg.act) if fkind == MLP
+                     else moe_lib.moe_apply(lp["ffn"], cfg, h2)[0])
+                xc = xc + f
         return xc, c_out
 
     cache: dict = {"t": jnp.full((B,), S, jnp.int32)}
@@ -378,27 +402,30 @@ def prefill(params: PyTree, cfg: ModelConfig, tokens: Array,
             xc, outs[f"l{j}"] = run_layer(pparams[f"l{j}"], xc, mixer, fkind)
         return xc, outs
 
-    if cfg.n_periods > 0:
-        if UNROLL_PERIODS:
-            outs = []
-            for i in range(cfg.n_periods):
-                x, o = period_body(x, _period_slice(params["periods"], i))
-                outs.append(o)
-            cache["periods"] = jax.tree.map(
-                lambda *xs: jnp.stack(xs), *outs)
-        else:
-            x, cache["periods"] = jax.lax.scan(period_body, x,
-                                               params["periods"])
-    base = cfg.n_periods * len(cfg.layer_pattern)
-    if cfg.n_remainder > 0:
-        cache["remainder"] = {}
-        for i in range(cfg.n_remainder):
-            mixer, fkind = _kind(cfg, base + i)
-            x, c_out = run_layer(params["remainder"][f"r{i}"], x, mixer,
-                                 fkind)
-            cache["remainder"][f"r{i}"] = c_out
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _lm_head(params, cfg, x)
+    with jax.named_scope("layers"):
+        if cfg.n_periods > 0:
+            if UNROLL_PERIODS:
+                outs = []
+                for i in range(cfg.n_periods):
+                    x, o = period_body(x,
+                                       _period_slice(params["periods"], i))
+                    outs.append(o)
+                cache["periods"] = jax.tree.map(
+                    lambda *xs: jnp.stack(xs), *outs)
+            else:
+                x, cache["periods"] = jax.lax.scan(period_body, x,
+                                                   params["periods"])
+        base = cfg.n_periods * len(cfg.layer_pattern)
+        if cfg.n_remainder > 0:
+            cache["remainder"] = {}
+            for i in range(cfg.n_remainder):
+                mixer, fkind = _kind(cfg, base + i)
+                x, c_out = run_layer(params["remainder"][f"r{i}"], x, mixer,
+                                     fkind)
+                cache["remainder"][f"r{i}"] = c_out
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = _lm_head(params, cfg, x)
     return logits, cache
 
 
@@ -425,30 +452,33 @@ def decode_step(params: PyTree, cfg: ModelConfig, token: Array,
             outs[f"l{j}"] = c_out if c_out is not None else pcache[f"l{j}"]
         return xc, outs
 
-    if cfg.n_periods > 0:
-        if UNROLL_PERIODS:
-            outs = []
-            for i in range(cfg.n_periods):
-                x, o = period_body(
-                    x, (_period_slice(params["periods"], i),
-                        _period_slice(cache["periods"], i)))
-                outs.append(o)
-            new_cache["periods"] = jax.tree.map(
-                lambda *xs: jnp.stack(xs), *outs)
-        else:
-            x, new_cache["periods"] = jax.lax.scan(
-                period_body, x, (params["periods"], cache["periods"]))
-    base = cfg.n_periods * len(cfg.layer_pattern)
-    if cfg.n_remainder > 0:
-        new_cache["remainder"] = {}
-        for i in range(cfg.n_remainder):
-            mixer, fkind = _kind(cfg, base + i)
-            x, c_out, _ = _apply_layer(
-                params["remainder"][f"r{i}"], cfg, x, mixer, fkind,
-                positions=positions, ctx=ctx,
-                cache=cache["remainder"][f"r{i}"], decode=True)
-            new_cache["remainder"][f"r{i}"] = (
-                c_out if c_out is not None else cache["remainder"][f"r{i}"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _lm_head(params, cfg, x)
+    with jax.named_scope("layers"):
+        if cfg.n_periods > 0:
+            if UNROLL_PERIODS:
+                outs = []
+                for i in range(cfg.n_periods):
+                    x, o = period_body(
+                        x, (_period_slice(params["periods"], i),
+                            _period_slice(cache["periods"], i)))
+                    outs.append(o)
+                new_cache["periods"] = jax.tree.map(
+                    lambda *xs: jnp.stack(xs), *outs)
+            else:
+                x, new_cache["periods"] = jax.lax.scan(
+                    period_body, x, (params["periods"], cache["periods"]))
+        base = cfg.n_periods * len(cfg.layer_pattern)
+        if cfg.n_remainder > 0:
+            new_cache["remainder"] = {}
+            for i in range(cfg.n_remainder):
+                mixer, fkind = _kind(cfg, base + i)
+                x, c_out, _ = _apply_layer(
+                    params["remainder"][f"r{i}"], cfg, x, mixer, fkind,
+                    positions=positions, ctx=ctx,
+                    cache=cache["remainder"][f"r{i}"], decode=True)
+                new_cache["remainder"][f"r{i}"] = (
+                    c_out if c_out is not None
+                    else cache["remainder"][f"r{i}"])
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = _lm_head(params, cfg, x)
     return logits, new_cache
