@@ -195,13 +195,13 @@ def cache_specs(cache_shapes: PyTree, B: int, dp_axes: tuple[str, ...],
         name = path.rsplit("/", 1)[-1]
         scanned = "periods" in path
         pre = (None,) if scanned else ()
-        if name in ("k", "v"):            # (B, KV, W, hd)
-            W = leaf.shape[2 + len(pre)]
+        if name in ("k", "v"):            # (B, W, KV*hd)
+            W = leaf.shape[1 + len(pre)]
             w_ax = "model" if (model_total > 1 and W % model_total == 0
                                and W >= model_total) else None
             if shard_batch:
-                return P(*pre, lead, None, w_ax, None)
-            return P(*pre, None, None, "data", None)
+                return P(*pre, lead, w_ax, None)
+            return P(*pre, None, "data", None)
         if name == "pos":                  # (B, W)
             W = leaf.shape[1 + len(pre)]
             w_ax = "model" if (model_total > 1 and W % model_total == 0

@@ -147,13 +147,18 @@ def self_attention(
     window: int = 0,              # 0 => global causal
     theta: float | None = None,
     cache: Optional[dict] = None,  # decode: {"k","v","pos"} rolling buffers
+    layer: tuple = (),            # decode: this layer's index into `cache`
 ) -> tuple[Array, Optional[dict]]:
     """Causal (optionally sliding-window) GQA self-attention.
 
     Train/prefill: cache is None -> attends within the sequence, returns the
     (rope-applied) K/V so the caller can build a cache.
-    Decode: cache given, S == 1 -> appends to the rolling buffer and attends
-    over it.
+    Decode: cache given, S == 1 -> writes the new token's K, V and position
+    into the rolling buffer and attends over it. ``k`` and ``v`` are
+    (..., B, W, KV*hd), one row per slot, and ``pos`` is (..., B, W);
+    ``layer`` indexes their leading axes (``(i,)`` for layer i of a stacked
+    cache, ``()`` for a buffer of its own). The whole buffers come back with
+    one row per batch element written in place.
     """
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
@@ -175,25 +180,34 @@ def self_attention(
         return o @ p["wo"], new_cache
 
     # ---------------- decode: S == 1, rolling buffer of width Wbuf
-    Wbuf = cache["k"].shape[2]                                 # (B,KV,W,hd)
+    Wbuf, C = cache["k"].shape[-2:]                            # C = KV*hd
     qpos = positions[:, 0]                                     # (B,)
-    slot = (qpos % Wbuf).astype(jnp.int32)
-    k_new = jnp.swapaxes(k, 1, 2)                              # (B,KV,1,hd)
-    v_new = jnp.swapaxes(v, 1, 2)
-    bidx = jnp.arange(B)
+    row = layer + (jnp.arange(B), (qpos % Wbuf).astype(jnp.int32))
     with jax.named_scope("kv_cache"):
-        ck = cache["k"].at[bidx, :, slot].set(k_new[:, :, 0])
-        cv = cache["v"].at[bidx, :, slot].set(v_new[:, :, 0])
-        cpos = cache["pos"].at[bidx, slot].set(qpos.astype(jnp.int32))
-    scores = _gqa_scores(q, jnp.swapaxes(ck, 1, 2))            # (B,KV,G,1,W)
-    tp = cpos[:, None, None, None, :]
-    qp = qpos[:, None, None, None, None]
+        ck = cache["k"].at[row].set(k.reshape(B, C))
+        cv = cache["v"].at[row].set(v.reshape(B, C))
+        cpos = cache["pos"].at[row].set(qpos.astype(jnp.int32))
+    # The layer's rows are read as stored, (B, W, KV*hd). Each query head
+    # is laid over the whole row, zero outside its own KV group, so its
+    # score sums its own group's products; of the weighted sum over the
+    # rows each head keeps its own group's part. The 0/1 products are
+    # exact, and both contractions over the cache run along the row's
+    # minor axis, so the cache needs no relayout.
+    own = (jnp.arange(C) // hd == jnp.arange(KV)[:, None, None]
+           ).astype(q.dtype)                                   # (KV,1,C)
+    spread = (jnp.arange(C)[:, None] % hd == jnp.arange(hd)
+              ).astype(q.dtype)                                # (C,hd)
+    qr = jnp.einsum("bkgd,cd->bkgc", q[:, 0], spread) * own    # (B,KV,G,C)
+    scores = jnp.einsum("bkgc,bwc->bkgw", qr, ck[layer])       # (B,KV,G,W)
+    tp = cpos[layer][:, None, None, :]
+    qp = qpos[:, None, None, None]
     mask = (tp >= 0) & (tp <= qp)
     if window:
         mask &= (qp - tp) < window
     scores = jnp.where(mask, scores, NEG_INF)
     w = _softmax(scores).astype(v.dtype)
-    o = _gqa_out(w, jnp.swapaxes(cv, 1, 2)).reshape(B, 1, H * hd)
+    o = jnp.einsum("bkgw,bwc->bkgc", w, cv[layer]) * own       # (B,KV,G,C)
+    o = jnp.einsum("bkgc,cd->bkgd", o, spread).reshape(B, 1, H * hd)
     return o @ p["wo"], {"k": ck, "v": cv, "pos": cpos}
 
 

@@ -5,15 +5,18 @@ Layers are grouped into *periods* (one repetition of `cfg.layer_pattern`);
 periods are executed with `jax.lax.scan` over stacked parameters so HLO size
 and compile time are independent of depth. Layers that do not fill a whole
 period are unrolled at the end ("remainder"). KV/SSM caches follow the same
-layout (leading n_periods axis), so prefill and decode also scan.
+layout (leading n_periods axis), so prefill and decode also scan. Decode
+carries the attention caches through the scan whole and writes each
+layer's new row into them in place (`decode_step`).
 
 Prefill and decode name their parts with `jax.named_scope`, which XLA keeps
 in each compiled op's ``op_name``: ``layers`` (the layer stack, and with it
-the scan's carry and its stacking of the cache), ``attention`` and ``mlp``
-(each sublayer with its norm and residual add), ``kv_cache`` (inside
-``attention``: the writes into the decode cache) and ``lm_head`` (final
-norm and head). A profiler trace attributes device time by these names
-(docs/serving.md, "Tracing the serving path").
+the scan's carry, its slicing of the weights and, in prefill, its stacking
+of the cache), ``attention`` and ``mlp`` (each sublayer with its norm and
+residual add), ``kv_cache`` (inside ``attention``: the writes into the
+decode cache) and ``lm_head`` (final norm and head). A profiler trace
+attributes device time by these names (docs/serving.md, "Tracing the
+serving path").
 """
 
 from __future__ import annotations
@@ -169,8 +172,11 @@ def _apply_layer(
     ctx: Optional[Array],
     cache: Optional[dict],
     decode: bool,
+    layer: tuple = (),
 ) -> tuple[Array, Optional[dict], Array]:
-    """One residual layer. Returns (x, cache_out, moe_aux)."""
+    """One residual layer. Returns (x, cache_out, moe_aux). An attention
+    layer's decode cache may be stacked: ``layer`` indexes its leading
+    axes, and the whole stack comes back with this layer's row written."""
     cache_out: Optional[dict] = None
     attn = mixer in (ATTN, ATTN_LOCAL)
     with _scope("attention", attn):
@@ -180,7 +186,7 @@ def _apply_layer(
             o, kv = attn_lib.self_attention(
                 lp["mixer"], cfg, h, positions=positions, window=window,
                 theta=_theta_for(cfg, mixer),
-                cache=cache if decode else None)
+                cache=cache if decode else None, layer=layer)
             cache_out = kv
         elif mixer == CROSS:
             o = attn_lib.cross_attention(lp["mixer"], cfg, h, ctx)
@@ -300,8 +306,8 @@ def _layer_cache(cfg: ModelConfig, mixer: str, B: int, S: int) -> dict:
         W = S if (mixer == ATTN or not cfg.sliding_window) \
             else min(cfg.sliding_window, S)
         return {
-            "k": jnp.zeros((B, cfg.n_kv_heads, W, hd), jnp.bfloat16),
-            "v": jnp.zeros((B, cfg.n_kv_heads, W, hd), jnp.bfloat16),
+            "k": jnp.zeros((B, W, cfg.n_kv_heads * hd), jnp.bfloat16),
+            "v": jnp.zeros((B, W, cfg.n_kv_heads * hd), jnp.bfloat16),
             "pos": jnp.full((B, W), -1, jnp.int32),
         }
     if mixer == MAMBA:
@@ -337,18 +343,18 @@ def init_cache(cfg: ModelConfig, B: int, S: int) -> PyTree:
 
 def _kv_to_buffer(kv: dict, W: int) -> dict:
     """Convert full-sequence K/V (B,S,KV,hd) into the rolling decode buffer
-    layout (B,KV,W,hd) + per-slot absolute positions."""
+    layout (B,W,KV*hd) + per-slot absolute positions."""
     k, v, pos = kv["k"], kv["v"], kv["pos"]
     B, S, KV, hd = k.shape
     take = min(W, S)
-    ks = jnp.swapaxes(k[:, S - take:], 1, 2)                  # (B,KV,take,hd)
-    vs = jnp.swapaxes(v[:, S - take:], 1, 2)
-    ptail = pos[:, S - take:]                                 # (B,take)
     slots = (jnp.arange(S - take, S, dtype=jnp.int32) % W)    # (take,)
-    bk = jnp.zeros((B, KV, W, hd), ks.dtype).at[:, :, slots].set(ks)
-    bv = jnp.zeros((B, KV, W, hd), vs.dtype).at[:, :, slots].set(vs)
-    bpos = jnp.full((B, W), -1, jnp.int32).at[:, slots].set(ptail)
-    return {"k": bk, "v": bv, "pos": bpos}
+
+    def buf(x):
+        tail = x[:, S - take:].reshape(B, take, KV * hd)
+        return jnp.zeros((B, W, KV * hd), x.dtype).at[:, slots].set(tail)
+
+    bpos = jnp.full((B, W), -1, jnp.int32).at[:, slots].set(pos[:, S - take:])
+    return {"k": buf(k), "v": buf(v), "pos": bpos}
 
 
 def prefill(params: PyTree, cfg: ModelConfig, tokens: Array,
@@ -434,50 +440,72 @@ def prefill(params: PyTree, cfg: ModelConfig, tokens: Array,
 def decode_step(params: PyTree, cfg: ModelConfig, token: Array,
                 cache: PyTree, ctx: Optional[Array] = None
                 ) -> tuple[Array, PyTree]:
-    """One greedy decode step. token: (B, 1) int32."""
-    B = token.shape[0]
+    """One greedy decode step. token: (B, 1) int32.
+
+    The attention layers' stacked caches are loop state: the period loop
+    carries them whole, and each layer writes one row of K, V and position
+    per batch element into them in place (`attention.self_attention`); no
+    layer's buffer and no stack is copied. The other layer kinds (Mamba
+    state, cross-attention's empty cache) are small and are handed from
+    period to period as scanned inputs and outputs."""
     x = embed_apply(params["embed"], token, cfg.embed_scale, cfg.d_model)
     positions = cache["t"][:, None]                            # (B,1)
     new_cache: dict = {"t": cache["t"] + 1}
+    inplace = {f"l{j}" for j, m in enumerate(cfg.layer_pattern)
+               if m in (ATTN, ATTN_LOCAL)}
 
-    def period_body(xc, scanned):
-        pparams, pcache = scanned
-        outs = {}
+    def period_body(xc, kv, pparams, pstate, i):
+        """Period i: ``kv`` holds the stacked attention caches (written at
+        row i), ``pstate`` the other layers' caches of this period."""
+        kv, outs = dict(kv), {}
         for j, mixer in enumerate(cfg.layer_pattern):
             _, fkind = _kind(cfg, j)
-            xc, c_out, _ = _apply_layer(
-                pparams[f"l{j}"], cfg, xc, mixer, fkind,
-                positions=positions, ctx=ctx,
-                cache=pcache[f"l{j}"], decode=True)
-            outs[f"l{j}"] = c_out if c_out is not None else pcache[f"l{j}"]
-        return xc, outs
+            name = f"l{j}"
+            if name in inplace:
+                xc, kv[name], _ = _apply_layer(
+                    pparams[name], cfg, xc, mixer, fkind,
+                    positions=positions, ctx=ctx, cache=kv[name],
+                    decode=True, layer=(i,))
+            else:
+                xc, outs[name], _ = _apply_layer(
+                    pparams[name], cfg, xc, mixer, fkind,
+                    positions=positions, ctx=ctx, cache=pstate[name],
+                    decode=True)
+        return xc, kv, outs
 
     with jax.named_scope("layers"):
         if cfg.n_periods > 0:
+            kv = {n: c for n, c in cache["periods"].items() if n in inplace}
+            rest = {n: c for n, c in cache["periods"].items()
+                    if n not in inplace}
             if UNROLL_PERIODS:
                 outs = []
                 for i in range(cfg.n_periods):
-                    x, o = period_body(
-                        x, (_period_slice(params["periods"], i),
-                            _period_slice(cache["periods"], i)))
+                    x, kv, o = period_body(
+                        x, kv, _period_slice(params["periods"], i),
+                        _period_slice(rest, i), i)
                     outs.append(o)
-                new_cache["periods"] = jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *outs)
+                rest = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
             else:
-                x, new_cache["periods"] = jax.lax.scan(
-                    period_body, x, (params["periods"], cache["periods"]))
+                def scan_body(carry, scanned):
+                    xc, kvc = carry
+                    xc, kvc, o = period_body(xc, kvc, *scanned)
+                    return (xc, kvc), o
+
+                (x, kv), rest = jax.lax.scan(
+                    scan_body, (x, kv),
+                    (params["periods"], rest,
+                     jnp.arange(cfg.n_periods, dtype=jnp.int32)))
+            new_cache["periods"] = {**kv, **rest}
         base = cfg.n_periods * len(cfg.layer_pattern)
         if cfg.n_remainder > 0:
             new_cache["remainder"] = {}
             for i in range(cfg.n_remainder):
                 mixer, fkind = _kind(cfg, base + i)
-                x, c_out, _ = _apply_layer(
+                x, new_cache["remainder"][f"r{i}"], _ = _apply_layer(
                     params["remainder"][f"r{i}"], cfg, x, mixer, fkind,
                     positions=positions, ctx=ctx,
                     cache=cache["remainder"][f"r{i}"], decode=True)
-                new_cache["remainder"][f"r{i}"] = (
-                    c_out if c_out is not None
-                    else cache["remainder"][f"r{i}"])
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = _lm_head(params, cfg, x)

@@ -73,36 +73,64 @@ def test_train_step_updates_and_finite(arch):
     assert bool(jnp.isfinite(loss2))
 
 
-@pytest.mark.parametrize("arch", ["granite-3-2b", "gemma3-1b",
-                                  "falcon-mamba-7b", "mixtral-8x7b",
-                                  "jamba-1.5-large-398b"])
-def test_prefill_decode_matches_forward(arch):
-    """Teacher-forced forward and prefill+decode must produce the same
-    next-token logits (validates cache correctness incl. rolling windows
-    and SSM state hand-off). MoE capacity is raised so no tokens drop —
+DECODE_ARCHS = ["granite-3-2b", "gemma3-1b", "falcon-mamba-7b",
+                "mixtral-8x7b", "jamba-1.5-large-398b", "chatglm3-6b"]
+N_DECODE = 6
+
+
+def _close(a, b):
+    np.testing.assert_allclose(np.asarray(a, np.float32),
+                               np.asarray(b, np.float32),
+                               rtol=2e-2, atol=2e-2)
+
+
+def _prefill_decode(cfg, params, tokens):
+    """Prefill all but the last N_DECODE tokens into a cache as wide as
+    the whole sequence, then decode those one step at a time. Returns
+    (prefill's last logits, each decode step's logits)."""
+    pre_logits, cache = prefill(params, cfg, tokens[:, : S - N_DECODE],
+                                cache_len=S)
+    step = jax.jit(lambda p, t, c: decode_step(p, cfg, t, c))
+    steps = []
+    for i in range(S - N_DECODE, S):
+        logits, cache = step(params, tokens[:, i: i + 1], cache)
+        steps.append(logits[:, 0])
+    return pre_logits[:, -1], steps
+
+
+@pytest.mark.parametrize("arch,unrolled", [
+    *(pytest.param(a, False, id=a) for a in DECODE_ARCHS),
+    *(pytest.param(a, True, id=f"{a}-unrolled") for a in DECODE_ARCHS)])
+def test_prefill_decode_matches_forward(arch, unrolled, monkeypatch):
+    """Teacher-forced forward and prefill + N_DECODE decode steps must
+    produce the same next-token logits at every step. This validates the
+    cache: rows written in place into the stacked buffer, rolling
+    sliding-window buffers past their width (gemma3's local layers hold 8
+    of 16 positions), partial rotary over 2 KV groups (chatglm3) and SSM
+    state hand-off. MoE capacity is raised so no tokens drop —
     capacity-based routing otherwise drops *different* tokens for different
-    total token counts, which is expected behaviour, not a cache bug."""
+    total token counts, which is expected behaviour, not a cache bug.
+    Unrolled, the period stack runs as a Python loop (`UNROLL_PERIODS`),
+    and its decode must give the scanned decode's logits (up to bf16
+    rounding: XLA fuses the two programs differently)."""
     import dataclasses
+    from repro.models import transformer
     cfg = dataclasses.replace(get_reduced(arch), capacity_factor=8.0)
     key = jax.random.PRNGKey(0)
     params = init_params(cfg, key)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab)
 
+    if unrolled:
+        _, scanned = _prefill_decode(cfg, params, tokens)
+        monkeypatch.setattr(transformer, "UNROLL_PERIODS", True)
     full_logits, _ = forward(params, cfg, tokens)
-    pre_logits, cache = prefill(params, cfg, tokens[:, : S - 2],
-                                cache_len=S)
-    np.testing.assert_allclose(
-        np.asarray(pre_logits[:, -1], np.float32),
-        np.asarray(full_logits[:, S - 3], np.float32), rtol=2e-2, atol=2e-2)
-    # decode the last two tokens and compare against teacher-forced logits
-    logits_a, cache = decode_step(params, cfg, tokens[:, S - 2: S - 1], cache)
-    np.testing.assert_allclose(
-        np.asarray(logits_a[:, 0], np.float32),
-        np.asarray(full_logits[:, S - 2], np.float32), rtol=2e-2, atol=2e-2)
-    logits_b, cache = decode_step(params, cfg, tokens[:, S - 1: S], cache)
-    np.testing.assert_allclose(
-        np.asarray(logits_b[:, 0], np.float32),
-        np.asarray(full_logits[:, S - 1], np.float32), rtol=2e-2, atol=2e-2)
+    pre_last, steps = _prefill_decode(cfg, params, tokens)
+    _close(pre_last, full_logits[:, S - N_DECODE - 1])
+    for i, logits in enumerate(steps):
+        _close(logits, full_logits[:, S - N_DECODE + i])
+    if unrolled:
+        for a, b in zip(steps, scanned):
+            _close(a, b)
 
 
 def test_param_count_matches_analytic():

@@ -106,3 +106,13 @@ def test_granite_decode_donates_its_cache(granite_steps):
     one step holds one copy of the KV cache."""
     m = granite_steps[1].memory_analysis()
     assert m.alias_size_in_bytes > 0
+
+
+def test_granite_decode_holds_no_second_cache(granite_steps):
+    """The decode step writes its cache in place: its scratch space is
+    smaller than one layer's K buffer, so no layer's buffer, let alone the
+    stack, is copied."""
+    cfg = get_config("granite-3-2b")
+    layer_k = 8 * (512 + 32) * cfg.n_kv_heads * cfg.resolved_head_dim * 2
+    m = granite_steps[1].memory_analysis()
+    assert m.temp_size_in_bytes < layer_k, m
