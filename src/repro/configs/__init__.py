@@ -17,6 +17,7 @@ _MODULES = {
     "falcon-mamba-7b": "repro.configs.falcon_mamba_7b",
     "llama-3.2-vision-11b": "repro.configs.llama_3_2_vision_11b",
     "seamless-m4t-medium": "repro.configs.seamless_m4t_medium",
+    "granite-4.0-h-micro": "repro.configs.granite_4_0_h_micro",
 }
 
 ARCH_IDS = tuple(_MODULES)

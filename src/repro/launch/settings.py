@@ -22,6 +22,7 @@ LONG_CONTEXT_ARCHS = {
     "mixtral-8x7b",     # SWA-4096 everywhere
     "jamba-1.5-large-398b",  # 63/72 layers O(1)-state mamba
     "falcon-mamba-7b",  # attention-free
+    "granite-4.0-h-micro",  # 36/40 layers O(1)-state mamba2
 }
 
 

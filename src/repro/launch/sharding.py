@@ -49,13 +49,13 @@ def _param_spec(path: str, ndim: int, dp) -> P:
     if leaf == "conv_w":
         return P(None, "model")            # (K, di)
     if leaf in ("conv_b", "dt_bias", "D"):
-        return P("model")                  # (di,)
+        return P("model")                  # (di,), mamba2 (H,)
     if leaf == "x_proj":
         return P("model", None)            # (di, dtr+2N)
     if leaf == "dt_proj":
         return P(None, "model")            # (dtr, di)
-    if leaf == "A_log":
-        return P("model", None)            # (di, N)
+    if leaf == "A_log":                    # mamba1 (di, N), mamba2 (H,)
+        return P("model", None) if ndim == 2 else P("model")
     return P()                             # norms, gates, scalars
 
 

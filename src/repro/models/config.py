@@ -11,6 +11,7 @@ from typing import Optional
 ATTN = "attn"            # global causal self-attention
 ATTN_LOCAL = "attn_local"  # sliding-window self-attention
 MAMBA = "mamba"          # mamba1 SSM block
+MAMBA2 = "mamba2"        # mamba2 (SSD) block: scalar decay per head
 CROSS = "cross"          # self-attention + gated cross-attention (VLM)
 
 # ffn kinds usable in `ffn_pattern`
@@ -46,6 +47,11 @@ class ModelConfig:
     ssm_conv: int = 4
     ssm_expand: int = 2
     dt_rank: int = 0                   # 0 => ceil(d_model / 16)
+    # --- mamba2 (ssm_state and ssm_conv are shared with mamba1) ---
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1                # each group's B, C serve H/G heads
+    ssm_chunk: int = 256               # SSD chunk length in prefill
     # --- encoder (enc-dec archs) ---
     encoder_layers: int = 0
     encoder_frames: int = 1024         # stub modality frontend length
@@ -76,6 +82,16 @@ class ModelConfig:
         return self.ssm_expand * self.d_model
 
     @property
+    def ssm_inner(self) -> int:
+        """Mamba-2 channels: heads x head dim."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of the Mamba-2 conv: x, then B and C of every group."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
     def resolved_dt_rank(self) -> int:
         return self.dt_rank or -(-self.d_model // 16)
 
@@ -97,7 +113,7 @@ class ModelConfig:
 
     @property
     def attention_free(self) -> bool:
-        return all(k == MAMBA for k in self.layer_pattern)
+        return all(k in (MAMBA, MAMBA2) for k in self.layer_pattern)
 
     def layer_kinds(self) -> list[tuple[str, str]]:
         """Full per-layer (mixer, ffn) kind list."""
@@ -132,6 +148,12 @@ class ModelConfig:
                 total += dtr * di + di                   # dt_proj, dt_bias
                 total += di * ns + di                    # A_log, D
                 total += di * d                          # out_proj
+            elif mixer == MAMBA2:
+                di, h, c = self.ssm_inner, self.ssm_heads, self.ssm_conv_dim
+                total += d * (di + c + h)                # in_proj: z, xBC, dt
+                total += self.ssm_conv * c + c           # conv_w, conv_b
+                total += 3 * h                           # dt_bias, A_log, D
+                total += di + di * d                     # norm, out_proj
             if ffn == MLP:
                 total += mlp_mats * d * f
             elif ffn == MOE:

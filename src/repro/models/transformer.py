@@ -6,17 +6,20 @@ periods are executed with `jax.lax.scan` over stacked parameters so HLO size
 and compile time are independent of depth. Layers that do not fill a whole
 period are unrolled at the end ("remainder"). KV/SSM caches follow the same
 layout (leading n_periods axis), so prefill and decode also scan. Decode
-carries the attention caches through the scan whole and writes each
-layer's new row into them in place (`decode_step`).
+carries the attention and Mamba-2 caches through the scan whole and writes
+each layer's new KV row, or its new state, into them in place
+(`decode_step`).
 
 Prefill and decode name their parts with `jax.named_scope`, which XLA keeps
 in each compiled op's ``op_name``: ``layers`` (the layer stack, and with it
 the scan's carry, its slicing of the weights and, in prefill, its stacking
-of the cache), ``attention`` and ``mlp`` (each sublayer with its norm and
-residual add), ``kv_cache`` (inside ``attention``: the writes into the
-decode cache) and ``lm_head`` (final norm and head). A profiler trace
-attributes device time by these names (docs/serving.md, "Tracing the
-serving path").
+of the cache), ``attention``, ``ssm`` (a Mamba-2 mixer) and ``mlp`` (each
+sublayer with its norm and residual add), ``kv_cache`` (inside
+``attention``: the writes into the decode cache), ``ssm_state`` (inside
+``ssm``: the read and in-place write of the carried state and conv ring,
+in prefill the cache built from the prompt) and ``lm_head`` (final norm
+and head). A profiler trace attributes device time by these names
+(docs/serving.md, "Tracing the serving path").
 """
 
 from __future__ import annotations
@@ -30,8 +33,19 @@ import jax.numpy as jnp
 
 from repro.models import attention as attn_lib
 from repro.models import mamba as mamba_lib
+from repro.models import mamba2 as mamba2_lib
 from repro.models import moe as moe_lib
-from repro.models.config import ATTN, ATTN_LOCAL, CROSS, MAMBA, MLP, MOE, NONE, ModelConfig
+from repro.models.config import (
+    ATTN,
+    ATTN_LOCAL,
+    CROSS,
+    MAMBA,
+    MAMBA2,
+    MLP,
+    MOE,
+    NONE,
+    ModelConfig,
+)
 from repro.models.layers import (
     dense_init,
     embed_apply,
@@ -70,6 +84,16 @@ def _scope(name: str, on: bool = True):
     return jax.named_scope(name) if on else contextlib.nullcontext()
 
 
+def _mixer_scope(mixer: str):
+    """The named scope of a mixer sublayer: ``attention`` or ``ssm``
+    (Mamba-2); the other kinds have none."""
+    if mixer in (ATTN, ATTN_LOCAL):
+        return jax.named_scope("attention")
+    if mixer == MAMBA2:
+        return jax.named_scope("ssm")
+    return contextlib.nullcontext()
+
+
 def _anchor(x: Array) -> Array:
     if ACT_SPEC is not None and x.ndim == len(ACT_SPEC):
         return jax.lax.with_sharding_constraint(x, ACT_SPEC)
@@ -88,6 +112,8 @@ def _init_layer(key, cfg: ModelConfig, mixer: str, ffn: str) -> dict:
         p["gate"] = jnp.zeros((), jnp.bfloat16)   # gated cross (llama-vision)
     elif mixer == MAMBA:
         p["mixer"] = mamba_lib.mamba_init(k1, cfg)
+    elif mixer == MAMBA2:
+        p["mixer"] = mamba2_lib.mamba2_init(k1, cfg)
     else:
         raise ValueError(f"unknown mixer {mixer!r}")
     if ffn == MLP:
@@ -174,12 +200,13 @@ def _apply_layer(
     decode: bool,
     layer: tuple = (),
 ) -> tuple[Array, Optional[dict], Array]:
-    """One residual layer. Returns (x, cache_out, moe_aux). An attention
-    layer's decode cache may be stacked: ``layer`` indexes its leading
-    axes, and the whole stack comes back with this layer's row written."""
+    """One residual layer. Returns (x, cache_out, moe_aux). An attention or
+    Mamba-2 layer's decode cache may be stacked: ``layer`` indexes its
+    leading axes, and the whole stack comes back with this layer's row (or
+    state) written."""
     cache_out: Optional[dict] = None
     attn = mixer in (ATTN, ATTN_LOCAL)
-    with _scope("attention", attn):
+    with _mixer_scope(mixer):
         h = rms_norm(x, lp["norm1"], cfg.norm_eps)
         if attn:
             window = cfg.sliding_window if mixer == ATTN_LOCAL else 0
@@ -200,6 +227,12 @@ def _apply_layer(
             else:
                 o = mamba_lib.mamba_forward(lp["mixer"], cfg, h)
                 cache_out = None  # prefill state handled separately
+        elif mixer == MAMBA2:
+            if decode:
+                o, cache_out = mamba2_lib.mamba2_decode_step(
+                    lp["mixer"], cfg, h, cache, layer=layer)
+            else:
+                o = mamba2_lib.mamba2_forward(lp["mixer"], cfg, h)
         else:
             raise ValueError(mixer)
         x = x + o
@@ -312,6 +345,8 @@ def _layer_cache(cfg: ModelConfig, mixer: str, B: int, S: int) -> dict:
         }
     if mixer == MAMBA:
         return mamba_lib.mamba_init_cache(cfg, B)
+    if mixer == MAMBA2:
+        return mamba2_lib.mamba2_init_cache(cfg, B)
     if mixer == CROSS:
         return {}
     raise ValueError(mixer)
@@ -369,7 +404,7 @@ def prefill(params: PyTree, cfg: ModelConfig, tokens: Array,
 
     def run_layer(lp, xc, mixer, fkind):
         attn = mixer in (ATTN, ATTN_LOCAL)
-        with _scope("attention", attn):
+        with _mixer_scope(mixer):
             h = rms_norm(xc, lp["norm1"], cfg.norm_eps)
             if attn:
                 window = cfg.sliding_window if mixer == ATTN_LOCAL else 0
@@ -388,6 +423,9 @@ def prefill(params: PyTree, cfg: ModelConfig, tokens: Array,
             elif mixer == MAMBA:
                 o, c_out = mamba_lib.mamba_forward(lp["mixer"], cfg, h,
                                                    return_state=True)
+            elif mixer == MAMBA2:
+                o, c_out = mamba2_lib.mamba2_forward(lp["mixer"], cfg, h,
+                                                     return_state=True)
             else:
                 raise ValueError(mixer)
             xc = xc + o
@@ -442,61 +480,64 @@ def decode_step(params: PyTree, cfg: ModelConfig, token: Array,
                 ) -> tuple[Array, PyTree]:
     """One greedy decode step. token: (B, 1) int32.
 
-    The attention layers' stacked caches are loop state: the period loop
-    carries them whole, and each layer writes one row of K, V and position
-    per batch element into them in place (`attention.self_attention`); no
-    layer's buffer and no stack is copied. The other layer kinds (Mamba
-    state, cross-attention's empty cache) are small and are handed from
-    period to period as scanned inputs and outputs."""
+    The attention and Mamba-2 layers' stacked caches are loop state: the
+    period loop carries them whole, and each layer writes one row of K, V
+    and position per batch element (`attention.self_attention`), or its
+    new SSM state and conv ring (`mamba2.mamba2_decode_step`), into them in
+    place at period i; no layer's buffer and no stack is copied. The other
+    layer kinds (Mamba-1 state, cross-attention's empty cache) are small
+    and are handed from period to period as scanned inputs and outputs."""
     x = embed_apply(params["embed"], token, cfg.embed_scale, cfg.d_model)
     positions = cache["t"][:, None]                            # (B,1)
     new_cache: dict = {"t": cache["t"] + 1}
     inplace = {f"l{j}" for j, m in enumerate(cfg.layer_pattern)
-               if m in (ATTN, ATTN_LOCAL)}
+               if m in (ATTN, ATTN_LOCAL, MAMBA2)}
 
-    def period_body(xc, kv, pparams, pstate, i):
-        """Period i: ``kv`` holds the stacked attention caches (written at
-        row i), ``pstate`` the other layers' caches of this period."""
-        kv, outs = dict(kv), {}
+    def period_body(xc, carried, pparams, pstate, i):
+        """Period i: ``carried`` holds the stacked attention and Mamba-2
+        caches (written at row i), ``pstate`` the other layers' caches of
+        this period."""
+        carried, outs = dict(carried), {}
         for j, mixer in enumerate(cfg.layer_pattern):
             _, fkind = _kind(cfg, j)
             name = f"l{j}"
             if name in inplace:
-                xc, kv[name], _ = _apply_layer(
+                xc, carried[name], _ = _apply_layer(
                     pparams[name], cfg, xc, mixer, fkind,
-                    positions=positions, ctx=ctx, cache=kv[name],
+                    positions=positions, ctx=ctx, cache=carried[name],
                     decode=True, layer=(i,))
             else:
                 xc, outs[name], _ = _apply_layer(
                     pparams[name], cfg, xc, mixer, fkind,
                     positions=positions, ctx=ctx, cache=pstate[name],
                     decode=True)
-        return xc, kv, outs
+        return xc, carried, outs
 
     with jax.named_scope("layers"):
         if cfg.n_periods > 0:
-            kv = {n: c for n, c in cache["periods"].items() if n in inplace}
+            carried = {n: c for n, c in cache["periods"].items()
+                       if n in inplace}
             rest = {n: c for n, c in cache["periods"].items()
                     if n not in inplace}
             if UNROLL_PERIODS:
                 outs = []
                 for i in range(cfg.n_periods):
-                    x, kv, o = period_body(
-                        x, kv, _period_slice(params["periods"], i),
+                    x, carried, o = period_body(
+                        x, carried, _period_slice(params["periods"], i),
                         _period_slice(rest, i), i)
                     outs.append(o)
                 rest = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
             else:
                 def scan_body(carry, scanned):
-                    xc, kvc = carry
-                    xc, kvc, o = period_body(xc, kvc, *scanned)
-                    return (xc, kvc), o
+                    xc, cc = carry
+                    xc, cc, o = period_body(xc, cc, *scanned)
+                    return (xc, cc), o
 
-                (x, kv), rest = jax.lax.scan(
-                    scan_body, (x, kv),
+                (x, carried), rest = jax.lax.scan(
+                    scan_body, (x, carried),
                     (params["periods"], rest,
                      jnp.arange(cfg.n_periods, dtype=jnp.int32)))
-            new_cache["periods"] = {**kv, **rest}
+            new_cache["periods"] = {**carried, **rest}
         base = cfg.n_periods * len(cfg.layer_pattern)
         if cfg.n_remainder > 0:
             new_cache["remainder"] = {}

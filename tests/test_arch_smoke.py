@@ -74,7 +74,8 @@ def test_train_step_updates_and_finite(arch):
 
 
 DECODE_ARCHS = ["granite-3-2b", "gemma3-1b", "falcon-mamba-7b",
-                "mixtral-8x7b", "jamba-1.5-large-398b", "chatglm3-6b"]
+                "mixtral-8x7b", "jamba-1.5-large-398b", "chatglm3-6b",
+                "granite-4.0-h-micro"]
 N_DECODE = 6
 
 
@@ -106,10 +107,12 @@ def test_prefill_decode_matches_forward(arch, unrolled, monkeypatch):
     produce the same next-token logits at every step. This validates the
     cache: rows written in place into the stacked buffer, rolling
     sliding-window buffers past their width (gemma3's local layers hold 8
-    of 16 positions), partial rotary over 2 KV groups (chatglm3) and SSM
-    state hand-off. MoE capacity is raised so no tokens drop —
-    capacity-based routing otherwise drops *different* tokens for different
-    total token counts, which is expected behaviour, not a cache bug.
+    of 16 positions), partial rotary over 2 KV groups (chatglm3), Mamba-1
+    state hand-off and Mamba-2 state written in place after a prefill over
+    two chunks (granite-4.0-h-micro). MoE capacity is raised so no tokens
+    drop — capacity-based routing otherwise drops *different* tokens for
+    different total token counts, which is expected behaviour, not a cache
+    bug.
     Unrolled, the period stack runs as a Python loop (`UNROLL_PERIODS`),
     and its decode must give the scanned decode's logits (up to bf16
     rounding: XLA fuses the two programs differently)."""
@@ -135,7 +138,8 @@ def test_prefill_decode_matches_forward(arch, unrolled, monkeypatch):
 
 def test_param_count_matches_analytic():
     """Analytic 6ND accounting must match the real parameter tree."""
-    for arch in ("granite-3-2b", "falcon-mamba-7b", "mixtral-8x7b"):
+    for arch in ("granite-3-2b", "falcon-mamba-7b", "mixtral-8x7b",
+                 "granite-4.0-h-micro"):
         cfg = get_reduced(arch)
         params = init_params(cfg, jax.random.PRNGKey(0))
         actual = sum(int(np.prod(l.shape))
@@ -157,6 +161,7 @@ def test_full_configs_param_counts():
         "falcon-mamba-7b": (6.5e9, 8.0e9),
         "llama-3.2-vision-11b": (9e9, 12e9),
         "seamless-m4t-medium": (0.55e9, 1.2e9),
+        "granite-4.0-h-micro": (3.1e9, 3.3e9),
     }
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()

@@ -1,8 +1,9 @@
 """Ahead-of-time compiles for one TPU v5e chip that is described, not
-attached: the Pallas kernels at real widths and granite-3-2b's full-width
-serving steps. The TPU compiler refuses here what the chip would refuse —
-unaligned blocks, kernels that overflow VMEM, programs that overflow HBM —
-at no chip time. Nothing runs, so nothing here is a time or a result.
+attached: the Pallas kernels at real widths, granite-3-2b's full-width
+serving steps and granite-4.0-h-micro's at its cell's shapes. The TPU
+compiler refuses here what the chip would refuse — unaligned blocks,
+kernels that overflow VMEM, programs that overflow HBM — at no chip
+time. Nothing runs, so nothing here is a time or a result.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -116,3 +117,33 @@ def test_granite_decode_holds_no_second_cache(granite_steps):
     layer_k = 8 * (512 + 32) * cfg.n_kv_heads * cfg.resolved_head_dim * 2
     m = granite_steps[1].memory_analysis()
     assert m.temp_size_in_bytes < layer_k, m
+
+
+@pytest.fixture(scope="module")
+def hybrid_steps(one_chip):
+    """granite-4.0-h-micro's prefill (B=32, P=512) and decode step over a
+    cache of 768, the shapes of its cell, compiled by `compile_steps`."""
+    cfg = get_config("granite-4.0-h-micro")
+    params = jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    prompts = _sds((32, 512), jnp.int32, one_chip)
+    return cfg, compile_steps(cfg, params, prompts, None, 768)
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_hybrid_step_fits_one_v5e(step, hybrid_steps):
+    compiled = hybrid_steps[1][0 if step == "prefill" else 1]
+    assert _device_bytes(compiled.memory_analysis()) < V5E_HBM_BYTES
+
+
+def test_hybrid_decode_carries_its_state_in_place(hybrid_steps):
+    """The decode step donates its cache, the 2.4 GB of Mamba-2 state
+    among it, and writes each layer's state in place: its scratch is
+    smaller than one layer's state slice (32·64·64·128 float32, 67 MB)."""
+    cfg, (_, decode) = hybrid_steps
+    state = 32 * cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4
+    n_mamba = sum(1 for m, _ in cfg.layer_kinds() if m == "mamba2")
+    m = decode.memory_analysis()
+    assert m.alias_size_in_bytes >= n_mamba * state
+    assert m.temp_size_in_bytes < state, m
